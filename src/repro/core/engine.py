@@ -508,9 +508,12 @@ class Engine:
             tiles=self.tiles))
 
     # ------------------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="engine.run")
     def run(self, algo: Algorithm, init_frontier: np.ndarray,
             init_state: dict) -> tuple[dict, Metrics, dict | None]:
-        """Execute ``algo`` to convergence; returns (state, metrics, trace)."""
+        """Execute ``algo`` to convergence; returns (state, metrics, trace).
+        The host span ``engine.run`` covers the dispatch and the host
+        copies of state and counters."""
         cfg = self.cfg
         front0 = jnp.asarray(np.asarray(init_frontier, dtype=bool))
         state0 = {k: jnp.asarray(v) for k, v in init_state.items()}
@@ -587,16 +590,18 @@ class Engine:
         tick = self._tick_fn(algo, tables)
         carry0 = self._initial_carry(algo, tables, front0, state0)
 
+        @jax.named_scope("schedule")
         def cond(c):
             return (c["t"] < cfg.max_ticks) & self._work_pending(c)
 
         def step(c):
             # solo: every submission is physical I/O — credit it as-is
             c2, aux = tick(c)
-            cnt = dict(c2["counters"])
-            cnt["io_ops"] = _c64_add(cnt["io_ops"], aux["io_ops"])
-            cnt["io_blocks"] = _c64_add(cnt["io_blocks"],
-                                        aux["io_blocks"])
+            with jax.named_scope("schedule"):
+                cnt = dict(c2["counters"])
+                cnt["io_ops"] = _c64_add(cnt["io_ops"], aux["io_ops"])
+                cnt["io_blocks"] = _c64_add(cnt["io_blocks"],
+                                            aux["io_blocks"])
             return dict(c2, counters=cnt)
 
         out = jax.lax.while_loop(cond, step, carry0)
@@ -615,6 +620,12 @@ class Engine:
         split each tick's submissions into physical vs shared reads
         across queries; the solo step credits them unchanged — same
         additions, same totals.
+
+        The stages run under ``jax.named_scope``s that a device profile
+        reads as each op's stage: ``schedule`` (1-3 and 7-10, O(B) block
+        work, and the loops' liveness tests), ``execute`` (4) and
+        ``refresh`` (5-6, the O(V) frontier and worklist update); the
+        per-query plane's map over rows adds ``rows`` around them.
         """
         cfg = self.cfg
         sched, pool, executor = self._tiers(tables)
@@ -631,170 +642,174 @@ class Engine:
             cnt = dict(c["counters"])
             busy0 = c["exec_busy"] if compute is not None else None
 
-            # ---- 1. async I/O completions (against device deadlines) ---
-            comp = sched.complete_io(c["b_state"], c["b_deadline"],
-                                     c["b_stamp"], t)
-            b_state, b_stamp = comp.b_state, comp.b_stamp
+            with jax.named_scope("schedule"):
+                # ---- 1. async I/O completions (against device deadlines) ---
+                comp = sched.complete_io(c["b_state"], c["b_deadline"],
+                                         c["b_stamp"], t)
+                b_state, b_stamp = comp.b_state, comp.b_stamp
 
-            # ---- 2. preload: priority queue over uncached blocks -------
-            pre = sched.preload(b_state, c["b_deadline"], b_prio, b_nactive,
-                                c["used_slots"], pool, t)
-            b_state, b_deadline = pre.b_state, pre.b_deadline
-            used_slots = pre.used_slots
-            # io_ops/io_blocks are credited by the caller from io_aux
-            # (the batch plane first dedups them across queries)
+                # ---- 2. preload: priority queue over uncached blocks ---
+                pre = sched.preload(b_state, c["b_deadline"], b_prio,
+                                    b_nactive, c["used_slots"], pool, t)
+                b_state, b_deadline = pre.b_state, pre.b_deadline
+                used_slots = pre.used_slots
+                # io_ops/io_blocks are credited by the caller from io_aux
+                # (the batch plane first dedups them across queries)
 
-            # ---- 3. pull: cached-queue policy --------------------------
-            # compute model: while the executor is busy chewing a prior
-            # pull's edge mass, no new pull happens (worklist zeroed ->
-            # nothing ready) but stages 1/2 above keep I/O flowing —
-            # the paper's compute/I/O overlap, now with real compute
-            # occupancy
-            pull_nact = b_nactive if compute is None else \
-                jnp.where(busy0 == 0, b_nactive, 0)
-            eidx, lane_valid, b_used = sched.pull(
-                b_state, pull_nact,
-                PullView(b_stamp=b_stamp, b_prio=b_prio,
-                         b_used=c["b_used"], t=t))
+                # ---- 3. pull: cached-queue policy ----------------------
+                # compute model: while the executor is busy chewing a prior
+                # pull's edge mass, no new pull happens (worklist zeroed ->
+                # nothing ready) but stages 1/2 above keep I/O flowing —
+                # the paper's compute/I/O overlap, now with real compute
+                # occupancy
+                pull_nact = b_nactive if compute is None else \
+                    jnp.where(busy0 == 0, b_nactive, 0)
+                eidx, lane_valid, b_used = sched.pull(
+                    b_state, pull_nact,
+                    PullView(b_stamp=b_stamp, b_prio=b_prio,
+                             b_used=c["b_used"], t=t))
 
-            # ---- 4. process: batched apply / propagation ---------------
-            res = executor.execute(algo, state, front, eidx, lane_valid)
-            state = res.state
-            if compute is not None:
-                # lanes run in parallel; the heaviest pulled block gates
-                # the batch. cost-1: this tick itself is the first busy
-                # tick of the new pull
-                lane_cost = compute.cost_ticks(tables.b_edges[eidx])
-                cost = jnp.max(jnp.where(lane_valid, lane_cost, 0))
-                busy1 = jnp.where(jnp.any(lane_valid),
-                                  jnp.maximum(cost - 1, 0),
-                                  jnp.maximum(busy0 - 1, 0))
+            with jax.named_scope("execute"):
+                # ---- 4. process: batched apply / propagation -----------
+                res = executor.execute(algo, state, front, eidx, lane_valid)
+                state = res.state
+                if compute is not None:
+                    # lanes run in parallel; the heaviest pulled block gates
+                    # the batch. cost-1: this tick itself is the first busy
+                    # tick of the new pull
+                    lane_cost = compute.cost_ticks(tables.b_edges[eidx])
+                    cost = jnp.max(jnp.where(lane_valid, lane_cost, 0))
+                    busy1 = jnp.where(jnp.any(lane_valid),
+                                      jnp.maximum(cost - 1, 0),
+                                      jnp.maximum(busy0 - 1, 0))
 
-            # ---- 5. submit: frontier update + reuse accounting ---------
-            front1 = front & ~res.processed
-            if cfg.sync:
-                front2 = front1
-                front_next = c["front_next"] | res.activated
-            else:
-                front2 = front1 | res.activated
-                front_next = c["front_next"]
-            cnt["reuse_activations"] = _c64_add(
-                cnt["reuse_activations"],
-                sched.resident_count(b_state, res.activated))
+            with jax.named_scope("refresh"):
+                # ---- 5. submit: frontier update + reuse accounting -----
+                front1 = front & ~res.processed
+                if cfg.sync:
+                    front2 = front1
+                    front_next = c["front_next"] | res.activated
+                else:
+                    front2 = front1 | res.activated
+                    front_next = c["front_next"]
+                cnt["reuse_activations"] = _c64_add(
+                    cnt["reuse_activations"],
+                    sched.resident_count(b_state, res.activated))
 
-            # ---- 6. worklist metadata refresh ---------------------------
-            if incremental:
-                b_nactive2, b_prio2, v_prio2 = sched.refresh_delta(
-                    algo, state, front2, c["v_prio"], b_prio, eidx,
-                    lane_valid)
-            else:
-                b_nactive2, b_prio2 = sched.refresh(algo, state, front2)
-            if check:
-                # today the counts half is vacuous (refresh_delta rebuilds
-                # counts with refresh's own prefix-sum primitive); it is
-                # kept so the witness automatically covers counts the day
-                # they become genuinely incremental. The priorities half
-                # is the live comparison.
-                nact_f, prio_f = sched.refresh(algo, state, front2)
-                mismatch = (jnp.sum(nact_f != b_nactive2)
-                            + jnp.sum(prio_f != b_prio2)).astype(i32)
-                if algo.priority_at is not None:
-                    # windowed-priority witness (PR 6): the threaded
-                    # v_prio must be exact at every frontier vertex —
-                    # the only positions future reductions read
-                    vp_f = algo.priority(state, tables.v_deg).astype(i32)
-                    mismatch = mismatch + jnp.sum(
-                        front2 & (vp_f != v_prio2)).astype(i32)
-
-            # ---- 7. finish: reactivated blocks re-enter cached queue ----
-            fin = sched.finish(b_state, b_stamp, c["b_reuse"], b_nactive2,
-                               eidx, lane_valid, used_slots, pool, t)
-            b_state, b_stamp = fin.b_state, fin.b_stamp
-            b_reuse, used_slots = fin.b_reuse, fin.used_slots
-            cnt["blocks_reused"] = _c64_add(cnt["blocks_reused"],
-                                            fin.blocks_reused)
-
-            # ---- 8. activation transitions for inactive blocks ----------
-            b_state, b_stamp = sched.activate(b_state, b_stamp, b_nactive2,
-                                              t)
-
-            # ---- 9. sync barrier (Sec. 4.3) ------------------------------
-            if cfg.sync:
-                (front2, front_next, b_state, b_nactive2, b_prio2,
-                 used_slots, barrier) = sched.barrier(
-                    algo, state, front2, front_next, b_state, b_nactive2,
-                    b_prio2, used_slots, pool, lazy=incremental)
-                cnt["barriers"] = _c64_add(cnt["barriers"],
-                                           barrier.astype(i32))
-
-            # ---- 10. counters & trace -----------------------------------
-            lanes_used = jnp.sum(lane_valid).astype(i32)
-            cnt["edges_scanned"] = _c64_add(cnt["edges_scanned"],
-                                            res.edges_scanned)
-            cnt["vertices_processed"] = _c64_add(cnt["vertices_processed"],
-                                                 res.vertices_processed)
-            idle = (lanes_used == 0) & jnp.any(front2)
-            if compute is not None:
-                # a busy executor is the opposite of an idle one: only
-                # ticks where it *could* have pulled and found nothing
-                # cached count as stalls
-                idle &= busy0 == 0
-                cnt["exec_busy_ticks"] = _c64_add(
-                    cnt["exec_busy_ticks"],
-                    ((busy0 > 0) | jnp.any(lane_valid)).astype(i32))
-            cnt["exec_idle_ticks"] = _c64_add(cnt["exec_idle_ticks"],
-                                              idle.astype(i32))
-            # io_active samples in-flight BEFORE completions so a tick
-            # whose last read retires still counts; the occupancy
-            # *integral* uses the post-completion count + submissions,
-            # which never double-counts a completion/submit handoff and
-            # is bounded by queue_depth
-            io_active = (comp.inflight + pre.io_ops > 0).astype(i32)
-            occ = pre.inflight + pre.io_ops
-            cnt["io_active_ticks"] = _c64_add(cnt["io_active_ticks"],
-                                              io_active)
-            cnt["inflight_ticks"] = _c64_add(cnt["inflight_ticks"], occ)
-            cnt["ticks"] = _c64_add(cnt["ticks"], jnp.ones((), i32))
-            cnt["block_passes"] = _c64_add(cnt["block_passes"],
-                                           lanes_used)
-            # peak residency is a MAX, not a sum: tracked in the low
-            # limb (used_slots is i32, never wraps)
-            cnt["peak_used_slots"] = (
-                cnt["peak_used_slots"][0],
-                jnp.maximum(cnt["peak_used_slots"][1],
-                            pre.used_slots.astype(jnp.uint32)))
-            trace = c["trace"]
-            if cfg.trace:
-                ti = jnp.minimum(t, TRACE_LEN - 1)
-                trace = {
-                    "io_blocks": trace["io_blocks"].at[ti].set(
-                        pre.io_blocks),
-                    "lanes": trace["lanes"].at[ti].set(lanes_used),
-                    "edges": trace["edges"].at[ti].set(res.edges_scanned),
-                    "frontier": trace["frontier"].at[ti].set(
-                        jnp.sum(front2).astype(i32)),
-                    "inflight": trace["inflight"].at[ti].set(occ),
-                    "io_active": trace["io_active"].at[ti].set(io_active),
-                    "used_slots": trace["used_slots"].at[ti].set(
-                        used_slots),
-                }
+                # ---- 6. worklist metadata refresh -----------------------
+                if incremental:
+                    b_nactive2, b_prio2, v_prio2 = sched.refresh_delta(
+                        algo, state, front2, c["v_prio"], b_prio, eidx,
+                        lane_valid)
+                else:
+                    b_nactive2, b_prio2 = sched.refresh(algo, state, front2)
                 if check:
-                    trace["refresh_mismatch"] = \
-                        c["trace"]["refresh_mismatch"].at[ti].set(mismatch)
+                    # today the counts half is vacuous (refresh_delta rebuilds
+                    # counts with refresh's own prefix-sum primitive); it is
+                    # kept so the witness automatically covers counts the day
+                    # they become genuinely incremental. The priorities half
+                    # is the live comparison.
+                    nact_f, prio_f = sched.refresh(algo, state, front2)
+                    mismatch = (jnp.sum(nact_f != b_nactive2)
+                                + jnp.sum(prio_f != b_prio2)).astype(i32)
+                    if algo.priority_at is not None:
+                        # windowed-priority witness: the threaded
+                        # v_prio must be exact at every frontier vertex —
+                        # the only positions future reductions read
+                        vp_f = algo.priority(state, tables.v_deg).astype(i32)
+                        mismatch = mismatch + jnp.sum(
+                            front2 & (vp_f != v_prio2)).astype(i32)
 
-            out_c = dict(state=state, front=front2, front_next=front_next,
-                         b_state=b_state, b_deadline=b_deadline,
-                         b_stamp=b_stamp,
-                         b_reuse=b_reuse, b_used=b_used,
-                         b_nactive=b_nactive2, b_prio=b_prio2,
-                         used_slots=used_slots, t=t + 1,
-                         counters=cnt, trace=trace)
-            if incremental:
-                out_c["v_prio"] = v_prio2
-            if compute is not None:
-                out_c["exec_busy"] = busy1
-            io_aux = dict(io_ops=pre.io_ops, io_blocks=pre.io_blocks,
-                          sub_mask=pre.sub_mask, sub_spans=pre.sub_spans)
+            with jax.named_scope("schedule"):
+                # ---- 7. finish: reactivated blocks re-enter cached queue ---
+                fin = sched.finish(b_state, b_stamp, c["b_reuse"], b_nactive2,
+                                   eidx, lane_valid, used_slots, pool, t)
+                b_state, b_stamp = fin.b_state, fin.b_stamp
+                b_reuse, used_slots = fin.b_reuse, fin.used_slots
+                cnt["blocks_reused"] = _c64_add(cnt["blocks_reused"],
+                                                fin.blocks_reused)
+
+                # ---- 8. activation transitions for inactive blocks ------
+                b_state, b_stamp = sched.activate(b_state, b_stamp, b_nactive2,
+                                                  t)
+
+                # ---- 9. sync barrier (Sec. 4.3) --------------------------
+                if cfg.sync:
+                    (front2, front_next, b_state, b_nactive2, b_prio2,
+                     used_slots, barrier) = sched.barrier(
+                        algo, state, front2, front_next, b_state, b_nactive2,
+                        b_prio2, used_slots, pool, lazy=incremental)
+                    cnt["barriers"] = _c64_add(cnt["barriers"],
+                                               barrier.astype(i32))
+
+                # ---- 10. counters & trace -------------------------------
+                lanes_used = jnp.sum(lane_valid).astype(i32)
+                cnt["edges_scanned"] = _c64_add(cnt["edges_scanned"],
+                                                res.edges_scanned)
+                cnt["vertices_processed"] = _c64_add(cnt["vertices_processed"],
+                                                     res.vertices_processed)
+                idle = (lanes_used == 0) & jnp.any(front2)
+                if compute is not None:
+                    # a busy executor is the opposite of an idle one: only
+                    # ticks where it *could* have pulled and found nothing
+                    # cached count as stalls
+                    idle &= busy0 == 0
+                    cnt["exec_busy_ticks"] = _c64_add(
+                        cnt["exec_busy_ticks"],
+                        ((busy0 > 0) | jnp.any(lane_valid)).astype(i32))
+                cnt["exec_idle_ticks"] = _c64_add(cnt["exec_idle_ticks"],
+                                                  idle.astype(i32))
+                # io_active samples in-flight BEFORE completions so a tick
+                # whose last read retires still counts; the occupancy
+                # *integral* uses the post-completion count + submissions,
+                # which never double-counts a completion/submit handoff and
+                # is bounded by queue_depth
+                io_active = (comp.inflight + pre.io_ops > 0).astype(i32)
+                occ = pre.inflight + pre.io_ops
+                cnt["io_active_ticks"] = _c64_add(cnt["io_active_ticks"],
+                                                  io_active)
+                cnt["inflight_ticks"] = _c64_add(cnt["inflight_ticks"], occ)
+                cnt["ticks"] = _c64_add(cnt["ticks"], jnp.ones((), i32))
+                cnt["block_passes"] = _c64_add(cnt["block_passes"],
+                                               lanes_used)
+                # peak residency is a MAX, not a sum: tracked in the low
+                # limb (used_slots is i32, never wraps)
+                cnt["peak_used_slots"] = (
+                    cnt["peak_used_slots"][0],
+                    jnp.maximum(cnt["peak_used_slots"][1],
+                                pre.used_slots.astype(jnp.uint32)))
+                trace = c["trace"]
+                if cfg.trace:
+                    ti = jnp.minimum(t, TRACE_LEN - 1)
+                    trace = {
+                        "io_blocks": trace["io_blocks"].at[ti].set(
+                            pre.io_blocks),
+                        "lanes": trace["lanes"].at[ti].set(lanes_used),
+                        "edges": trace["edges"].at[ti].set(res.edges_scanned),
+                        "frontier": trace["frontier"].at[ti].set(
+                            jnp.sum(front2).astype(i32)),
+                        "inflight": trace["inflight"].at[ti].set(occ),
+                        "io_active": trace["io_active"].at[ti].set(io_active),
+                        "used_slots": trace["used_slots"].at[ti].set(
+                            used_slots),
+                    }
+                    if check:
+                        trace["refresh_mismatch"] = \
+                            c["trace"]["refresh_mismatch"].at[ti].set(mismatch)
+
+                out_c = dict(state=state, front=front2, front_next=front_next,
+                             b_state=b_state, b_deadline=b_deadline,
+                             b_stamp=b_stamp,
+                             b_reuse=b_reuse, b_used=b_used,
+                             b_nactive=b_nactive2, b_prio=b_prio2,
+                             used_slots=used_slots, t=t + 1,
+                             counters=cnt, trace=trace)
+                if incremental:
+                    out_c["v_prio"] = v_prio2
+                if compute is not None:
+                    out_c["exec_busy"] = busy1
+                io_aux = dict(io_ops=pre.io_ops, io_blocks=pre.io_blocks,
+                              sub_mask=pre.sub_mask, sub_spans=pre.sub_spans)
             return out_c, io_aux
 
         return tick
@@ -932,6 +947,7 @@ class Engine:
         i32 = jnp.int32
         tick = self._tick_fn(algo, tables)
 
+        @jax.named_scope("rows")
         def step(c):
             alive = self._batch_alive(c)
             # residency at the START of the tick (post-finish of the
@@ -969,6 +985,7 @@ class Engine:
         carry0 = self._batch_carry0(algo, tables, fronts0, states0)
         step = self._batch_step_fn(algo, tables)
 
+        @jax.named_scope("schedule")
         def cond(c):
             return jnp.any(self._batch_alive(c))
 
@@ -1056,6 +1073,7 @@ class Engine:
         carry0 = self._agg_carry0(algo, tables, fronts0, states0)
         tick = self._agg_tick_fn(algo, tables, self._agg_slots(Q))
 
+        @jax.named_scope("schedule")
         def cond(c):
             return (c["t"] < cfg.max_ticks) & self._agg_pending(c)
 
@@ -1084,144 +1102,149 @@ class Engine:
             t = c["t"]
             cnt = dict(c["counters"])
             busy0 = c["exec_busy"] if compute is not None else None
-            nact_agg, prio_agg = Scheduler.aggregate_worklist(
-                c["b_nactive"], c["b_prio"], cfg.agg_fairness)
 
-            # ---- 1. async I/O completions ------------------------------
-            comp = sched.complete_io(c["b_state"], c["b_deadline"],
-                                     c["b_stamp"], t)
-            b_state, b_stamp = comp.b_state, comp.b_stamp
+            with jax.named_scope("schedule"):
+                nact_agg, prio_agg = Scheduler.aggregate_worklist(
+                    c["b_nactive"], c["b_prio"], cfg.agg_fairness)
 
-            # ---- 2. preload against the MERGED worklist ----------------
-            pre = sched.preload(b_state, c["b_deadline"], prio_agg,
-                                nact_agg, c["used_slots"], pool, t)
-            b_state, b_deadline = pre.b_state, pre.b_deadline
-            used_slots = pre.used_slots
+                # ---- 1. async I/O completions --------------------------
+                comp = sched.complete_io(c["b_state"], c["b_deadline"],
+                                         c["b_stamp"], t)
+                b_state, b_stamp = comp.b_state, comp.b_stamp
 
-            # ---- 3. ONE pull for the whole batch (compute-gated) -------
-            pull_nact = nact_agg if compute is None else \
-                jnp.where(busy0 == 0, nact_agg, 0)
-            eidx, lane_valid, b_used = sched.pull(
-                b_state, pull_nact,
-                PullView(b_stamp=b_stamp, b_prio=prio_agg,
-                         b_used=c["b_used"], t=t))
+                # ---- 2. preload against the MERGED worklist ------------
+                pre = sched.preload(b_state, c["b_deadline"], prio_agg,
+                                    nact_agg, c["used_slots"], pool, t)
+                b_state, b_deadline = pre.b_state, pre.b_deadline
+                used_slots = pre.used_slots
 
-            # ---- 4. ONE executor pass per block, Q-stacked state -------
-            res = executor.execute_many(algo, state, front, eidx,
-                                        lane_valid)
-            state = res.state
-            if compute is not None:
-                lane_cost = compute.cost_ticks(tables.b_edges[eidx])
-                cost = jnp.max(jnp.where(lane_valid, lane_cost, 0))
-                busy1 = jnp.where(jnp.any(lane_valid),
-                                  jnp.maximum(cost - 1, 0),
-                                  jnp.maximum(busy0 - 1, 0))
+                # ---- 3. ONE pull for the whole batch (compute-gated) ---
+                pull_nact = nact_agg if compute is None else \
+                    jnp.where(busy0 == 0, nact_agg, 0)
+                eidx, lane_valid, b_used = sched.pull(
+                    b_state, pull_nact,
+                    PullView(b_stamp=b_stamp, b_prio=prio_agg,
+                             b_used=c["b_used"], t=t))
 
-            # ---- 5. per-query frontier update + reuse accounting -------
-            front2 = (front & ~res.processed) | res.activated
-            reuse_q = jax.vmap(lambda a: sched.resident_count(
-                b_state, a))(res.activated)
+            with jax.named_scope("execute"):
+                # ---- 4. ONE executor pass per block, Q-stacked state ---
+                res = executor.execute_many(algo, state, front, eidx,
+                                            lane_valid)
+                state = res.state
+                if compute is not None:
+                    lane_cost = compute.cost_ticks(tables.b_edges[eidx])
+                    cost = jnp.max(jnp.where(lane_valid, lane_cost, 0))
+                    busy1 = jnp.where(jnp.any(lane_valid),
+                                      jnp.maximum(cost - 1, 0),
+                                      jnp.maximum(busy0 - 1, 0))
 
-            # ---- 6. per-query worklist refresh (lax.map keeps the
-            # incremental full-rebuild lax.cond a real branch) -----------
-            if incremental:
-                nact2, prio2, v_prio2 = jax.lax.map(
-                    lambda a: sched.refresh_delta(
-                        algo, a[0], a[1], a[2], a[3], eidx, lane_valid),
-                    (state, front2, c["v_prio"], c["b_prio"]))
-            else:
-                nact2, prio2 = jax.lax.map(
-                    lambda a: sched.refresh(algo, a[0], a[1]),
-                    (state, front2))
-            if check:
-                nact_f, prio_f = jax.lax.map(
-                    lambda a: sched.refresh(algo, a[0], a[1]),
-                    (state, front2))
-                mismatch = (jnp.sum(nact_f != nact2)
-                            + jnp.sum(prio_f != prio2)).astype(i32)
-                if algo.priority_at is not None:
-                    vp_f = jax.lax.map(
-                        lambda st: algo.priority(
-                            st, tables.v_deg).astype(i32), state)
-                    mismatch = mismatch + jnp.sum(
-                        front2 & (vp_f != v_prio2)).astype(i32)
-            nact2_agg = jnp.sum(nact2, axis=0)
+            with jax.named_scope("refresh"):
+                # ---- 5. per-query frontier update + reuse accounting ---
+                front2 = (front & ~res.processed) | res.activated
+                reuse_q = jax.vmap(lambda a: sched.resident_count(
+                    b_state, a))(res.activated)
 
-            # ---- 7./8. finish + activation on the cross-query refcount -
-            fin = sched.finish(b_state, b_stamp, c["b_reuse"],
-                               nact2_agg, eidx, lane_valid, used_slots,
-                               pool, t)
-            b_state, b_stamp = fin.b_state, fin.b_stamp
-            b_reuse, used_slots = fin.b_reuse, fin.used_slots
-            b_state, b_stamp = sched.activate(b_state, b_stamp,
-                                              nact2_agg, t)
-
-            # ---- 10. counters & trace: schedule-wide values broadcast
-            # into every query's accumulators, _PER_QUERY_COUNTERS from
-            # each query's own masks (see batch_totals) ------------------
-            lanes_used = jnp.sum(lane_valid).astype(i32)
-            cnt["io_ops"] = _c64_add(cnt["io_ops"], pre.io_ops)
-            cnt["io_blocks"] = _c64_add(cnt["io_blocks"], pre.io_blocks)
-            cnt["edges_scanned"] = _c64_add(cnt["edges_scanned"],
-                                            res.edges_scanned)
-            cnt["vertices_processed"] = _c64_add(
-                cnt["vertices_processed"], res.vertices_processed)
-            cnt["reuse_activations"] = _c64_add(cnt["reuse_activations"],
-                                                reuse_q)
-            cnt["blocks_reused"] = _c64_add(cnt["blocks_reused"],
-                                            fin.blocks_reused)
-            idle = (lanes_used == 0) & jnp.any(front2)
-            if compute is not None:
-                idle &= busy0 == 0
-                cnt["exec_busy_ticks"] = _c64_add(
-                    cnt["exec_busy_ticks"],
-                    ((busy0 > 0) | jnp.any(lane_valid)).astype(i32))
-            cnt["exec_idle_ticks"] = _c64_add(cnt["exec_idle_ticks"],
-                                              idle.astype(i32))
-            io_active = (comp.inflight + pre.io_ops > 0).astype(i32)
-            occ = pre.inflight + pre.io_ops
-            cnt["io_active_ticks"] = _c64_add(cnt["io_active_ticks"],
-                                              io_active)
-            cnt["inflight_ticks"] = _c64_add(cnt["inflight_ticks"], occ)
-            cnt["ticks"] = _c64_add(cnt["ticks"], jnp.ones((), i32))
-            cnt["block_passes"] = _c64_add(cnt["block_passes"],
-                                           lanes_used)
-            cnt["peak_used_slots"] = (
-                cnt["peak_used_slots"][0],
-                jnp.maximum(cnt["peak_used_slots"][1],
-                            pre.used_slots.astype(jnp.uint32)))
-            trace = c["trace"]
-            if cfg.trace:
-                ti = jnp.minimum(t, TRACE_LEN - 1)
-                trace = {
-                    "io_blocks": trace["io_blocks"].at[ti].set(
-                        pre.io_blocks),
-                    "lanes": trace["lanes"].at[ti].set(lanes_used),
-                    "edges": trace["edges"].at[ti].set(
-                        jnp.sum(res.edges_scanned).astype(i32)),
-                    "frontier": trace["frontier"].at[ti].set(
-                        jnp.sum(front2).astype(i32)),
-                    "inflight": trace["inflight"].at[ti].set(occ),
-                    "io_active": trace["io_active"].at[ti].set(
-                        io_active),
-                    "used_slots": trace["used_slots"].at[ti].set(
-                        used_slots),
-                }
+                # ---- 6. per-query worklist refresh (lax.map keeps the
+                # incremental full-rebuild lax.cond a real branch) -----------
+                if incremental:
+                    nact2, prio2, v_prio2 = jax.lax.map(
+                        lambda a: sched.refresh_delta(
+                            algo, a[0], a[1], a[2], a[3], eidx, lane_valid),
+                        (state, front2, c["v_prio"], c["b_prio"]))
+                else:
+                    nact2, prio2 = jax.lax.map(
+                        lambda a: sched.refresh(algo, a[0], a[1]),
+                        (state, front2))
                 if check:
-                    trace["refresh_mismatch"] = \
-                        c["trace"]["refresh_mismatch"].at[ti].set(
-                            mismatch)
+                    nact_f, prio_f = jax.lax.map(
+                        lambda a: sched.refresh(algo, a[0], a[1]),
+                        (state, front2))
+                    mismatch = (jnp.sum(nact_f != nact2)
+                                + jnp.sum(prio_f != prio2)).astype(i32)
+                    if algo.priority_at is not None:
+                        vp_f = jax.lax.map(
+                            lambda st: algo.priority(
+                                st, tables.v_deg).astype(i32), state)
+                        mismatch = mismatch + jnp.sum(
+                            front2 & (vp_f != v_prio2)).astype(i32)
+                nact2_agg = jnp.sum(nact2, axis=0)
 
-            out_c = dict(state=state, front=front2, b_state=b_state,
-                         b_deadline=b_deadline, b_stamp=b_stamp,
-                         b_reuse=b_reuse, b_used=b_used,
-                         b_nactive=nact2, b_prio=prio2,
-                         used_slots=used_slots, t=t + 1,
-                         counters=cnt, trace=trace)
-            if incremental:
-                out_c["v_prio"] = v_prio2
-            if compute is not None:
-                out_c["exec_busy"] = busy1
+            with jax.named_scope("schedule"):
+                # ---- 7./8. finish + activation on the cross-query refcount
+                fin = sched.finish(b_state, b_stamp, c["b_reuse"],
+                                   nact2_agg, eidx, lane_valid, used_slots,
+                                   pool, t)
+                b_state, b_stamp = fin.b_state, fin.b_stamp
+                b_reuse, used_slots = fin.b_reuse, fin.used_slots
+                b_state, b_stamp = sched.activate(b_state, b_stamp,
+                                                  nact2_agg, t)
+
+                # ---- 10. counters & trace: schedule-wide values broadcast
+                # into every query's accumulators, _PER_QUERY_COUNTERS from
+                # each query's own masks (see batch_totals) ------------------
+                lanes_used = jnp.sum(lane_valid).astype(i32)
+                cnt["io_ops"] = _c64_add(cnt["io_ops"], pre.io_ops)
+                cnt["io_blocks"] = _c64_add(cnt["io_blocks"], pre.io_blocks)
+                cnt["edges_scanned"] = _c64_add(cnt["edges_scanned"],
+                                                res.edges_scanned)
+                cnt["vertices_processed"] = _c64_add(
+                    cnt["vertices_processed"], res.vertices_processed)
+                cnt["reuse_activations"] = _c64_add(cnt["reuse_activations"],
+                                                    reuse_q)
+                cnt["blocks_reused"] = _c64_add(cnt["blocks_reused"],
+                                                fin.blocks_reused)
+                idle = (lanes_used == 0) & jnp.any(front2)
+                if compute is not None:
+                    idle &= busy0 == 0
+                    cnt["exec_busy_ticks"] = _c64_add(
+                        cnt["exec_busy_ticks"],
+                        ((busy0 > 0) | jnp.any(lane_valid)).astype(i32))
+                cnt["exec_idle_ticks"] = _c64_add(cnt["exec_idle_ticks"],
+                                                  idle.astype(i32))
+                io_active = (comp.inflight + pre.io_ops > 0).astype(i32)
+                occ = pre.inflight + pre.io_ops
+                cnt["io_active_ticks"] = _c64_add(cnt["io_active_ticks"],
+                                                  io_active)
+                cnt["inflight_ticks"] = _c64_add(cnt["inflight_ticks"], occ)
+                cnt["ticks"] = _c64_add(cnt["ticks"], jnp.ones((), i32))
+                cnt["block_passes"] = _c64_add(cnt["block_passes"],
+                                               lanes_used)
+                cnt["peak_used_slots"] = (
+                    cnt["peak_used_slots"][0],
+                    jnp.maximum(cnt["peak_used_slots"][1],
+                                pre.used_slots.astype(jnp.uint32)))
+                trace = c["trace"]
+                if cfg.trace:
+                    ti = jnp.minimum(t, TRACE_LEN - 1)
+                    trace = {
+                        "io_blocks": trace["io_blocks"].at[ti].set(
+                            pre.io_blocks),
+                        "lanes": trace["lanes"].at[ti].set(lanes_used),
+                        "edges": trace["edges"].at[ti].set(
+                            jnp.sum(res.edges_scanned).astype(i32)),
+                        "frontier": trace["frontier"].at[ti].set(
+                            jnp.sum(front2).astype(i32)),
+                        "inflight": trace["inflight"].at[ti].set(occ),
+                        "io_active": trace["io_active"].at[ti].set(
+                            io_active),
+                        "used_slots": trace["used_slots"].at[ti].set(
+                            used_slots),
+                    }
+                    if check:
+                        trace["refresh_mismatch"] = \
+                            c["trace"]["refresh_mismatch"].at[ti].set(
+                                mismatch)
+
+                out_c = dict(state=state, front=front2, b_state=b_state,
+                             b_deadline=b_deadline, b_stamp=b_stamp,
+                             b_reuse=b_reuse, b_used=b_used,
+                             b_nactive=nact2, b_prio=prio2,
+                             used_slots=used_slots, t=t + 1,
+                             counters=cnt, trace=trace)
+                if incremental:
+                    out_c["v_prio"] = v_prio2
+                if compute is not None:
+                    out_c["exec_busy"] = busy1
             return out_c
 
         return tick
@@ -1297,8 +1320,9 @@ class Engine:
 
             def step(tables, c):
                 c2 = self._agg_tick_fn(algo, tables, slots)(c)
-                return (c2, jnp.any(c2["front"], axis=-1),
-                        c2["used_slots"], jnp.zeros(Q, bool))
+                with jax.named_scope("schedule"):
+                    return (c2, jnp.any(c2["front"], axis=-1),
+                            c2["used_slots"], jnp.zeros(Q, bool))
 
             def carry0(tables, fronts0, states0):
                 return self._agg_carry0(algo, tables, fronts0, states0)
@@ -1340,9 +1364,10 @@ class Engine:
         else:
             def step(tables, c):
                 c2 = self._batch_step_fn(algo, tables)(c)
-                alive = self._batch_alive(c2)
-                return (c2, alive, jnp.sum(c2["used_slots"]),
-                        self._work_pending(c2) & ~alive)
+                with jax.named_scope("schedule"):
+                    alive = self._batch_alive(c2)
+                    return (c2, alive, jnp.sum(c2["used_slots"]),
+                            self._work_pending(c2) & ~alive)
 
             def carry0(tables, fronts0, states0):
                 return self._batch_carry0(algo, tables, fronts0, states0)

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.api import Algorithm
+from repro.core.prefix import cumsum
 from repro.kernels.ops import frontier_relax
 
 
@@ -334,7 +335,7 @@ class GatherExecutor(ExecutorBackend):
                 tile):
         t = self.t
         i32 = jnp.int32
-        cum_e = jnp.cumsum(degs, axis=1)
+        cum_e = cumsum(degs, axis=1)
         tot = cum_e[:, -1]
         slots = jnp.arange(tile.We, dtype=i32)
         # comparing every slot with every vertex is a few vector passes

@@ -30,6 +30,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.prefix import cumsum
+
 
 class BufferPool:
     """Slot accounting over a fixed pool of ``slots`` 4 KB units.
@@ -62,7 +64,7 @@ class BufferPool:
         ``spans[i]`` slots are granted to candidate i while the running
         total fits in the free capacity. Returns ``(take, used_slots')``.
         """
-        cum_sp = jnp.cumsum(spans * want)
+        cum_sp = cumsum(spans * want)
         take = want & (cum_sp <= self.free(used_slots))
         return take, used_slots + jnp.sum(spans * take)
 

@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.executor import edge_window
 from repro.core.pool import BufferPool
+from repro.core.prefix import cumsum
 from repro.io_sim.device import DeviceModel
 
 # persistent per-tick block states (PROCESSING/REACTIVATED are intra-tick)
@@ -64,9 +65,9 @@ def _prefix_sum(x):
     a million elements (this form: about two seconds)."""
     n = x.shape[0]
     if n <= _SUM_ROW:
-        return jnp.cumsum(x)
+        return cumsum(x)
     rows = -(-n // _SUM_ROW)
-    part = jnp.cumsum(jnp.pad(x, (0, rows * _SUM_ROW - n)).reshape(
+    part = cumsum(jnp.pad(x, (0, rows * _SUM_ROW - n)).reshape(
         rows, _SUM_ROW), axis=1)
     tot = part[:, -1]
     return (part + (_prefix_sum(tot) - tot)[:, None]).reshape(-1)[:n]

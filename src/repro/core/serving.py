@@ -67,16 +67,27 @@ stays 0 (asserted by ``benchmarks/bench_service.py``'s CI gate).
 Multi-pass queries that override ``Query.execute`` (``MIS``) need host
 barriers between engine passes and cannot join the continuous loop;
 ``submit`` rejects them — route those through ``GraphService.drain``.
+
+**Profiler spans.** A :meth:`~ContinuousService.step` records host spans
+(``jax.profiler.TraceAnnotation``, free while no trace runs) on the
+profiler's clock: ``serve.admit`` (with ``serve.resize`` inside when the
+ladder moves), ``serve.advance`` holding ``serve.dispatch`` (the compiled
+step's call) and ``serve.flags`` (the blocking read of the liveness
+flags), and ``serve.retire`` holding ``serve.extract`` (a retired row's
+host copies and result) and ``serve.kill`` (the row's reset), so a trace
+puts each stretch of device idle down to the host code that spent it.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.api import (Algorithm, Query, QueryBatch, QueryState,
                             aggregation_eligible)
@@ -235,10 +246,11 @@ class ContinuousService:
         retired = self._advance()
         self.clock += 1
         out = []
-        for g in list(self._groups.values()):
-            out.extend(self._retire_rows(g, retired.get(g.key, ())))
-            if self.serve.shrink:
-                self._maybe_shrink(g)
+        with TraceAnnotation("serve.retire"):
+            for g in list(self._groups.values()):
+                out.extend(self._retire_rows(g, retired.get(g.key, ())))
+                if self.serve.shrink:
+                    self._maybe_shrink(g)
         return out
 
     def run_until_idle(self, max_ticks: int = 1_000_000) -> None:
@@ -277,6 +289,7 @@ class ContinuousService:
             self._groups[key] = g
         return g
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.admit")
     def _admit_queued(self, busy: bool) -> None:
         still = []
         for handle, algo in self._queue:
@@ -322,6 +335,8 @@ class ContinuousService:
     # ------------------------------------------------------------------
     # the tick
     # ------------------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function,
+                       name="serve.advance")
     def _advance(self) -> dict:
         """Advance live groups one engine tick each; returns
         ``{group key: row indices retired by this tick}``."""
@@ -348,12 +363,14 @@ class ContinuousService:
                 self.throttled_group_ticks += 1
                 continue
             before = g.used_slots
-            carry, *flags = g.fns["step"](g.carry)
+            with TraceAnnotation("serve.dispatch"):
+                carry, *flags = g.fns["step"](g.carry)
             g.carry = carry
-            # one device-to-host round trip for the tick's three flags
-            pending, used, stalled = jax.device_get(flags)
-            pend = np.array(pending)  # writable host copy
-            raise_if_stalled(g.algo, self.session.cfg, stalled)
+            with TraceAnnotation("serve.flags"):
+                # one device-to-host round trip for the tick's three flags
+                pending, used, stalled = jax.device_get(flags)
+                pend = np.array(pending)  # writable host copy
+                raise_if_stalled(g.algo, self.session.cfg, stalled)
             g.used_slots = int(used)
             used_total += g.used_slots - before
             advanced += 1
@@ -373,8 +390,10 @@ class ContinuousService:
         out = []
         for q in slots:
             handle = g.rows[q]
-            result = self._extract_row(g, q, handle)
-            self._kill_row(g, q)
+            with TraceAnnotation("serve.extract"):
+                result = self._extract_row(g, q, handle)
+            with TraceAnnotation("serve.kill"):
+                self._kill_row(g, q)
             handle.retire_tick = self.clock
             handle._resolve(result)
             self._latencies.append(handle.latency_ticks)
@@ -427,6 +446,7 @@ class ContinuousService:
             perm = [q for q, h in enumerate(g.rows) if h is not None]
             self._resize(g, perm, target, g.algo)
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.resize")
     def _resize(self, g: _Group, perm: list[int], newcap: int,
                 algo: Algorithm) -> None:
         """Move ``g`` to capacity ``newcap``, gathering the live rows in

@@ -85,8 +85,13 @@ def _chip_smoke(*args, env=None):
 
 def test_chip_smoke_cpu_rehearsal_reaches_ok_line(tmp_path):
     cache = tmp_path / "jax_cache"
+    # JAX keeps only programs that took a second or more to compile; at
+    # scale 8 the CPU compiles the service step in about one, so every
+    # program is kept here, else whether the cache is written is chance
     proc = _chip_smoke("--cpu-rehearsal", "--scale", "8",
-                       env={"JAX_COMPILATION_CACHE_DIR": str(cache)})
+                       env={"JAX_COMPILATION_CACHE_DIR": str(cache),
+                            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS":
+                                "0"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["ok"] is True
