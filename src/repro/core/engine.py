@@ -1293,13 +1293,24 @@ class Engine:
             row's frontier/worklist and release residency no live query
             needs (:meth:`Scheduler.reclaim_idle`), so a service that
             never drains gives slots back at retirement instead of
-            ratcheting the shared pool full.
+            ratcheting the shared pool full;
+          * ``extract(carry, q) -> row`` — row ``q`` sliced on the
+            device, so retirement copies one row to the host, not Q:
+            ``state`` (each leaf's row), ``counters`` (every counter's
+            ``(hi, lo)`` limbs as one uint32 ``[K, 2]`` array, counters
+            in sorted-name order) and, on the per-query plane with
+            ``cfg.trace`` set, ``trace`` (each key's full row; the host
+            cuts it to the row's ticks). The aggregated plane's state
+            and counters are per-query leaves too
+            (:attr:`AGG_PER_QUERY_KEYS`); its trace is the shared
+            schedule's and is left out.
 
         Each is a compiled program taking the engine's tables as its
         first argument, bound here. Compiled once per ``(Q, mode, name,
         params, cfg)`` and cached — admissions and retirements at a
-        given capacity never recompile; capacity changes (the serving
-        layer's power-of-two ladder) do.
+        given capacity never recompile (``q`` is traced, so one program
+        serves every row); capacity changes (the serving layer's
+        power-of-two ladder) do.
         """
         key = ("svc", mode, Q, algo.name, algo.params, self.cfg)
         if key in self._compiled:
@@ -1387,10 +1398,23 @@ class Engine:
             # states), which also zeroes its private pool accounting
             retire = None
 
+        row_trace = self.cfg.trace and mode == "per_query"
+
+        def extract(tables, c, q):
+            cnt = c["counters"]
+            row = dict(
+                state={k: v[q] for k, v in c["state"].items()},
+                counters=jnp.stack([jnp.stack([cnt[k][0][q], cnt[k][1][q]])
+                                    for k in sorted(cnt)]))
+            if row_trace:
+                row["trace"] = {k: v[q] for k, v in c["trace"].items()}
+            return row
+
         bound = lambda f: functools.partial(jax.jit(f), self.tables)
         fns = dict(carry0=bound(carry0), step=bound(step),
                    admit=bound(admit),
-                   retire=None if retire is None else bound(retire))
+                   retire=None if retire is None else bound(retire),
+                   extract=bound(extract))
         self._compiled[key] = fns
         return fns
 

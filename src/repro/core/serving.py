@@ -27,9 +27,11 @@ admitted*. On the aggregated plane only the per-query leaves are
 replaced and the newcomer's frontier blocks are re-activated against the
 shared schedule's cross-query refcount — the running pull order absorbs
 the new worklist without restarting. Retirement is the reverse edge:
-the moment a row's liveness flag drops, the host extracts its state and
-counters into a full :class:`~repro.core.session.RunResult`, resolves
-the handle, and kills the row — the service keeps ticking throughout.
+the moment a row's liveness flag drops, the device slices out the row's
+state and counters (``service_fns``'s ``extract``), the host copies that
+one row and builds a full :class:`~repro.core.session.RunResult`,
+resolves the handle, and kills the row — the service keeps ticking
+throughout.
 
 **Capacity ladder.** The compiled step is shaped ``[Q, ...]``, so Q is a
 compile-time constant. Capacities move on a power-of-two ladder
@@ -74,8 +76,9 @@ profiler's clock: ``serve.admit`` (with ``serve.resize`` inside when the
 ladder moves), ``serve.advance`` holding ``serve.dispatch`` (the compiled
 step's call) and ``serve.flags`` (the blocking read of the liveness
 flags), and ``serve.retire`` holding ``serve.extract`` (a retired row's
-host copies and result) and ``serve.kill`` (the row's reset), so a trace
-puts each stretch of device idle down to the host code that spent it.
+slice on the device, its one copy to the host, and its result) and
+``serve.kill`` (the row's reset), so a trace puts each stretch of device
+idle down to the host code that spent it.
 """
 from __future__ import annotations
 
@@ -204,6 +207,7 @@ class ContinuousService:
         self.resizes = 0
         self.peak_capacity = 0
         self.peak_service_slots = 0
+        self.retire_host_bytes = 0        # bytes retirement copied out
 
     # ------------------------------------------------------------------
     @property
@@ -403,18 +407,20 @@ class ContinuousService:
 
     def _extract_row(self, g: _Group, q: int,
                      handle: QueryHandle) -> RunResult:
-        carry = g.carry
-        state = {k: np.asarray(v)[q] for k, v in carry["state"].items()}
-        counters = {k: (int(np.asarray(hi)[q]) << 32)
-                    | int(np.asarray(lo)[q])
-                    for k, (hi, lo) in carry["counters"].items()}
-        metrics = Metrics(**counters)
+        # row q is sliced on the device and copied in one transfer
+        row = jax.device_get(g.fns["extract"](g.carry, q))
+        self.retire_host_bytes += sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(row))
+        state = row["state"]
+        names = sorted(g.carry["counters"])   # extract's counter order
+        metrics = Metrics(**{k: (int(hi) << 32) | int(lo)
+                             for k, (hi, lo) in zip(names, row["counters"])})
         trace = None
-        if self.session.cfg.trace and g.mode == "per_query":
+        if "trace" in row:
             # aggregated-plane traces describe the ONE shared schedule,
             # not this row — only the per-query plane has a row trace
-            trace = {k: np.asarray(v)[q][:min(metrics.ticks, TRACE_LEN)]
-                     for k, v in carry["trace"].items()}
+            trace = {k: v[:min(metrics.ticks, TRACE_LEN)]
+                     for k, v in row["trace"].items()}
         algo = g.algos[q] or g.algo
         extracted = algo.extract(state, self.session.ctx)
         return self.session._wrap(handle.query, extracted, state,
@@ -519,7 +525,8 @@ class ContinuousService:
                  throttled_group_ticks=self.throttled_group_ticks,
                  resizes=self.resizes,
                  peak_capacity=self.peak_capacity,
-                 peak_service_slots=self.peak_service_slots)
+                 peak_service_slots=self.peak_service_slots,
+                 retire_host_bytes=self.retire_host_bytes)
         pct = self.latency_percentiles()
         d["latency_ticks_p50"] = pct["p50"]
         d["latency_ticks_p99"] = pct["p99"]

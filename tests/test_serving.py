@@ -18,6 +18,7 @@ The contracts under test:
   * **Compile once per capacity** — steady-state admissions and
     retirements at a fixed capacity add no compile-cache entries.
 """
+import dataclasses
 import functools
 
 import numpy as np
@@ -26,6 +27,7 @@ import pytest
 from repro.algorithms import BFS, MIS, PPR, WCC
 from repro.core import (ContinuousService, EngineConfig, GraphService,
                         GraphSession, QueryBatch, QueryState, ServeConfig)
+from repro.core.engine import TRACE_LEN, Metrics
 from repro.storage.csr import symmetrize
 from repro.storage.rmat import rmat_graph
 
@@ -156,12 +158,13 @@ def test_conservation_at_every_q_transition():
 # compile once per capacity
 # ----------------------------------------------------------------------
 
-def test_steady_state_admissions_never_recompile():
+def test_steady_state_admissions_never_recompile(monkeypatch):
     svc = _service(ServeConfig(initial_capacity=2, max_capacity=2))
     svc.submit(BFS(SOURCES[0]))
     svc.submit(BFS(SOURCES[1]))
     svc.run_until_idle()
     compiled = svc.session.num_compiled
+    slots = _check_retirements(monkeypatch, svc)
     # a second wave at the same capacity — admission, stepping and
     # retirement reuse every compiled fn
     for s in SOURCES[2:]:
@@ -169,6 +172,120 @@ def test_steady_state_admissions_never_recompile():
     svc.run_until_idle()
     assert svc.session.num_compiled == compiled
     assert svc.stats()["completed"] == len(SOURCES)
+    # rows retired at every slot, and the row slice compiled once: the
+    # slot is traced, not a constant of the program
+    assert set(slots) == {0, 1}
+    (g,) = svc._groups.values()
+    assert g.fns["extract"].func._cache_size() == 1
+
+
+# ----------------------------------------------------------------------
+# retirement copies one row: the device slice equals the full-leaf read
+# ----------------------------------------------------------------------
+
+def _full_leaf_row(g, q, trace: bool) -> tuple:
+    """Row ``q`` read as retirement read it before the device slice:
+    each whole ``[Q, ...]`` leaf copied to the host, then indexed."""
+    c = g.carry
+    state = {k: np.asarray(v)[q] for k, v in c["state"].items()}
+    metrics = Metrics(**{k: (int(np.asarray(hi)[q]) << 32)
+                         | int(np.asarray(lo)[q])
+                         for k, (hi, lo) in c["counters"].items()})
+    rows = None
+    if trace and g.mode == "per_query":
+        n = min(metrics.ticks, TRACE_LEN)
+        rows = {k: np.asarray(v)[q][:n] for k, v in c["trace"].items()}
+    return state, metrics, rows
+
+
+def _check_retirements(monkeypatch, svc) -> list:
+    """Wrap ``svc._extract_row`` so that each retired row must equal,
+    bit for bit, the row the full-leaf reads give; returns the retired
+    slots in order."""
+    slots = []
+    real = svc._extract_row
+
+    def checked(g, q, handle):
+        state, metrics, rows = _full_leaf_row(g, q, svc.session.cfg.trace)
+        result = real(g, q, handle)
+        for k in state:
+            assert np.array_equal(result.state[k], state[k]), k
+        assert result.metrics == metrics
+        if rows is None:
+            assert result.trace is None
+        else:
+            assert result.trace.keys() == rows.keys()
+            for k in rows:
+                assert np.array_equal(result.trace[k], rows[k]), k
+        slots.append(q)
+        return result
+    monkeypatch.setattr(svc, "_extract_row", checked)
+    return slots
+
+
+def _solo_io(m: Metrics) -> Metrics:
+    """``m`` with its shared I/O folded back into the physical counts:
+    what a solo run of the row's query counts."""
+    return dataclasses.replace(
+        m, io_ops=m.io_ops + m.io_ops_shared, io_ops_shared=0,
+        io_blocks=m.io_blocks + m.io_blocks_shared, io_blocks_shared=0)
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("plane", ["per_query", "aggregated"])
+def test_retired_row_equals_full_leaf_read(monkeypatch, plane, trace):
+    """A row sliced on the device and copied alone is the row the old
+    full-leaf reads gave, bit for bit: state, every counter and the
+    row's trace. On the per-query plane it is also the solo run."""
+    kw = dict(AGG) if plane == "aggregated" else {}
+    g = _graph(True)
+    svc = ContinuousService(
+        GraphSession(g, EngineConfig(**CFG, **kw, trace=trace),
+                     block_edges=64),
+        serve=ServeConfig(initial_capacity=4, max_capacity=4))
+    queries = [BFS(s) for s in SOURCES[:4]]
+    if plane == "per_query":
+        queries += [PPR(source=s, alpha=0.15, r_max=1e-3)
+                    for s in SOURCES[:3]]
+    slots = _check_retirements(monkeypatch, svc)
+    handles = [svc.submit(qy) for qy in queries]
+    svc.run_until_idle()
+    assert all(grp.mode == plane for grp in svc._groups.values())
+    assert len(slots) == len(queries) and max(slots) > 0
+    if plane == "aggregated":
+        return
+    sess = GraphSession(g, EngineConfig(**CFG, trace=trace), block_edges=64)
+    for qy, h in zip(queries, handles):
+        solo, got = sess.run(qy), h.result()
+        assert np.array_equal(got.result, solo.result), qy
+        for k in solo.state:
+            assert np.array_equal(got.state[k], solo.state[k]), (qy, k)
+        assert _solo_io(got.metrics) == solo.metrics, qy
+        if trace:
+            assert got.trace.keys() == solo.trace.keys()
+            for k in solo.trace:
+                assert np.array_equal(got.trace[k], solo.trace[k]), (qy, k)
+        else:
+            assert got.trace is None and solo.trace is None
+
+
+def test_retirement_copies_one_row_to_the_host():
+    """``retire_host_bytes`` counts one row's state and counters per
+    retired query — not the ``[Q, ...]`` leaves it came from."""
+    svc = ContinuousService(
+        GraphSession(_graph(True), EngineConfig(**CFG), block_edges=64),
+        serve=ServeConfig(initial_capacity=4, max_capacity=4))
+    for s in SOURCES:
+        svc.submit(PPR(source=s, alpha=0.15, r_max=1e-3))
+    svc.run_until_idle()
+    (g,) = svc._groups.values()
+    state_bytes = sum(v.nbytes for v in g.state_zero.values())
+    assert state_bytes == 2 * 4 * svc.session.engine.V   # p, r: f32[V]
+    counter_bytes = len(dataclasses.fields(Metrics)) * 2 * 4  # u32 limbs
+    st = svc.stats()
+    assert st["completed"] == len(SOURCES)
+    assert st["retire_host_bytes"] == \
+        st["completed"] * (state_bytes + counter_bytes)
 
 
 # ----------------------------------------------------------------------
