@@ -128,7 +128,16 @@ def ppr_batch(sources, alpha: float = 0.15,
 @dataclasses.dataclass(frozen=True)
 class PageRank(Query):
     """PageRank = PPR with uniform initial distribution (footnote 1);
-    ``result`` = estimates indexed by ORIGINAL vertex id."""
+    ``result`` = estimates indexed by ORIGINAL vertex id.
+
+    The uniform start puts ``1 / n`` on every original vertex, isolated
+    ones included: each of those keeps ``alpha / n`` and drops the rest.
+    ``r_max`` is absolute, as in PPR: push stops once every residual
+    ``r(v) <= r_max * deg(v)``, so the residual mass left, and with it
+    the bound ``||p - p*||_1 <= r_max * sum(deg)`` on the distance to
+    the exact PageRank ``p*`` (dangling mass dropped), grows with the
+    graph's edge count. A guarantee
+    on that mass sets ``r_max = mass / sum(deg)``."""
 
     alpha: float = 0.15
     r_max: float = 1e-7

@@ -8,12 +8,18 @@ from __future__ import annotations
 
 import collections
 import os
+import pathlib
+import sys
 
 import numpy as np
 import pytest
 
 from repro.storage.csr import CSRGraph, from_edges, symmetrize
 from repro.storage.rmat import rmat_graph
+
+# the benchmark's numpy references (``bench/``), appended so that none of
+# its module names shadows another
+sys.path.append(str(pathlib.Path(__file__).resolve().parents[1] / "bench"))
 
 
 # ----------------------------------------------------------------------
@@ -144,6 +150,36 @@ def oracle_ppr(g: CSRGraph, r0: np.ndarray, alpha: float, r_max: float
                     in_q[v] = True
                     active.append(v)
     return p, r
+
+
+#: float32 rounding of an answer's push invariant, as a share of the
+#: terms of ``r(v)`` (``pagerank_reference.violation``): each push adds
+#: a rounding of 2**-24 to ``p(v)``; sound answers read 7e-8 to 3.2e-7
+#: on Kronecker graphs of scales 8 to 16
+PAGERANK_F32_TOL = 2e-6
+
+
+def check_pagerank(plain, p, alpha: float, r_max: float) -> float:
+    """Hold whole-graph PageRank answer ``p`` to the benchmark's plain
+    reference on ``plain`` (a ``graph500.PlainGraph``): the push
+    invariant to float32 rounding, and ``||p - p*||_1`` within the
+    guarantee ``r_max * sum(deg)``, with no tolerance. Returns the L1
+    error."""
+    import pagerank_reference as ref
+
+    u = ref.uniform(plain.n)
+    assert ref.violation(plain, u, alpha, r_max, p) <= PAGERANK_F32_TOL
+    l1 = ref.l1_error(p, ref.fixed_point(plain, u, alpha))
+    assert l1 <= ref.guarantee(plain, r_max)
+    return l1
+
+
+def plain_of(g: CSRGraph):
+    """``g``'s own CSR as the references' ``PlainGraph``: directed,
+    degrees out-degrees."""
+    from graph500 import PlainGraph
+
+    return PlainGraph(g.indptr.astype(np.int64), g.indices.astype(np.int64))
 
 
 def check_is_mis(g: CSRGraph, mis: np.ndarray) -> None:

@@ -11,8 +11,8 @@ from repro.core.session import GraphSession
 from repro.storage.csr import symmetrize
 from repro.storage.hybrid import build_hybrid
 
-from conftest import (check_is_mis, oracle_bfs, oracle_kcore, oracle_ppr,
-                      oracle_wcc, small_graph)
+from conftest import (check_is_mis, check_pagerank, oracle_bfs, oracle_kcore,
+                      oracle_ppr, oracle_wcc, plain_of, small_graph)
 
 
 def make_session(g, sync=False, **kw):
@@ -104,7 +104,7 @@ def test_pagerank_converges():
     g = small_graph(n=150, m=1200, seed=5)
     res = make_session(g).run(PageRank(r_max=1e-5))
     assert res.result.sum() <= 1.0 + 1e-5
-    assert res.result.sum() > 0.3  # most mass converted
+    check_pagerank(plain_of(g), res.result, 0.15, 1e-5)
     assert res.metrics.ticks > 0
 
 
