@@ -104,7 +104,7 @@ def main() -> None:
         # internal, accessed through the session it belongs to
         eng, hg = sess.engine, sess.hg
         v_sched = np.asarray(eng.tables.v_sched).copy()
-        v_sched[~sess.ctx.is_real] = -1
+        v_sched[~np.asarray(sess.ctx.is_real)] = -1
         orig_sched = v_sched[sess.ctx.v2id]
 
         if algo_name == "bfs":

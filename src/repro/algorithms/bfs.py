@@ -59,18 +59,12 @@ class BFS(Query):
     source: int
 
     def build(self) -> Algorithm:
-        source = self.source
-
-        def init(ctx: AlgoContext):
-            src = ctx.engine_id(source)
-            dis0 = np.full(ctx.V, INF32, dtype=np.int32)
-            dis0[src] = 0
-            front0 = np.zeros(ctx.V, dtype=bool)
-            front0[src] = True
-            return front0, {"dis": dis0}
+        def init(ctx: AlgoContext, src):
+            front0 = jnp.arange(ctx.V) == src
+            return front0, {"dis": jnp.where(front0, jnp.int32(0), INF32)}
 
         def extract(state: StateT, ctx: AlgoContext):
             return np.asarray(state["dis"])[ctx.v2id]
 
         return dataclasses.replace(bfs_algorithm(), init=init,
-                                   extract=extract)
+                                   extract=extract, source=self.source)

@@ -54,9 +54,9 @@ class KCore(Query):
     def build(self) -> Algorithm:
         k = self.k
 
-        def init(ctx: AlgoContext):
+        def init(ctx: AlgoContext, src):
             # current-degree state over the engine id space
-            deg0 = ctx.degrees.astype(np.int32).copy()
+            deg0 = jnp.asarray(ctx.degrees, jnp.int32)
             # foreachVertex: activate vertices with initial degree < k
             front0 = (deg0 < k) & ctx.is_real
             return front0, {"deg": deg0}

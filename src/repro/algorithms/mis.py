@@ -64,7 +64,7 @@ class MIS(Query):
         V = ctx.V
         rng = np.random.default_rng(self.seed)
         label = np.full(V, INF32, dtype=np.int32)
-        is_real = ctx.is_real
+        is_real = np.asarray(ctx.is_real)
         real_ids = np.where(is_real)[0]
         label[real_ids] = rng.permutation(
             real_ids.shape[0]).astype(np.int32)

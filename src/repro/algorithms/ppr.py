@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -60,20 +61,36 @@ def ppr_algorithm(alpha: float = 0.15, r_max: float = 1e-6) -> Algorithm:
                      params=(alpha, r_max))
 
 
-def _push_spec(alpha: float, r_max: float, make_r0) -> Algorithm:
-    """Forward-push spec with init/extract hooks; ``make_r0(ctx)`` builds
-    the initial residual distribution in the engine vertex domain."""
+def _last_active_degree(r0: float, r_max: float) -> int:
+    """Largest int32 degree ``d`` at which a vertex holding residual
+    ``r0`` starts active, or -1 if none does.
 
-    def init(ctx: AlgoContext):
-        r0 = make_r0(ctx).astype(np.float32)
-        front0 = (r0 > r_max * ctx.degrees) & ctx.is_real
-        return front0, {"p": np.zeros(ctx.V, np.float32), "r": r0}
+    The start rule is ``r0 > r_max * d`` evaluated in float64, as numpy
+    evaluates it for an int32 degree table. The test is monotone in
+    ``d``, so a start frontier reads ``degrees <= d`` in integers on
+    the device, with the float64 products taken here on the host once.
+    """
+    active = lambda d: float(r0) > float(r_max) * d
+    lo, hi = 0, 2 ** 31 - 1
+    if not active(lo):
+        return -1
+    if active(hi):
+        return hi
+    while hi - lo > 1:           # active(lo), not active(hi)
+        mid = (lo + hi) // 2
+        lo, hi = (mid, hi) if active(mid) else (lo, mid)
+    return lo
+
+
+def _push_spec(alpha: float, r_max: float, **hooks) -> Algorithm:
+    """Forward-push spec with ``init`` (among ``hooks``) and the
+    ``extract`` both starts share."""
 
     def extract(state: StateT, ctx: AlgoContext):
         return np.asarray(state["p"])[ctx.v2id]
 
-    return dataclasses.replace(ppr_algorithm(alpha, r_max), init=init,
-                               extract=extract)
+    return dataclasses.replace(ppr_algorithm(alpha, r_max),
+                               extract=extract, **hooks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,34 +104,31 @@ class PPR(Query):
     r_max: float = 1e-6
 
     def build(self) -> Algorithm:
-        source = self.source
+        last = _last_active_degree(1.0, self.r_max)
 
-        def make_r0(ctx: AlgoContext):
-            r0 = np.zeros(ctx.V, dtype=np.float32)
-            r0[ctx.engine_id(source)] = 1.0
-            return r0
+        def init(ctx: AlgoContext, src):
+            hit = jnp.arange(ctx.V) == src
+            r0 = jnp.where(hit, jnp.float32(1), jnp.float32(0))
+            front0 = hit & (ctx.degrees <= last) & ctx.is_real
+            return front0, {"p": jnp.zeros(ctx.V, jnp.float32), "r": r0}
 
-        return _push_spec(self.alpha, self.r_max, make_r0)
+        return _push_spec(self.alpha, self.r_max, init=init,
+                          source=self.source)
 
 
 @dataclasses.dataclass(frozen=True)
 class PPRBatch(QueryBatch):
     """N-personalization PPR — the paper's inherently per-user workload
-    — with a *vectorized* batched init: the [Q, V] residual matrix is
-    built in one shot instead of stacking Q per-query inits. The arrays
-    are element-identical to the auto-lifted hooks (same dtypes, same
-    threshold test), so results keep the solo-equivalence contract.
-    Build with :func:`ppr_batch`.
+    — with a *vectorized* batched init: the members' one ``init``
+    mapped over their sources at once instead of stacking Q separate
+    calls. The arrays are element-identical to the auto-lifted hooks,
+    so results keep the solo-equivalence contract. Build with
+    :func:`ppr_batch`.
     """
 
     def init_batch(self, algos, ctx: AlgoContext):
-        Q = len(self.queries)
-        srcs = np.array([ctx.engine_id(q.source) for q in self.queries])
-        r0 = np.zeros((Q, ctx.V), dtype=np.float32)
-        r0[np.arange(Q), srcs] = 1.0
-        r_max = self.queries[0].r_max
-        front0 = (r0 > r_max * ctx.degrees[None, :]) & ctx.is_real[None, :]
-        return front0, {"p": np.zeros((Q, ctx.V), np.float32), "r": r0}
+        srcs = jnp.stack([a.init_data(ctx) for a in algos])
+        return jax.vmap(lambda s: algos[0].init(ctx, s))(srcs)
 
 
 def ppr_batch(sources, alpha: float = 0.15,
@@ -143,9 +157,18 @@ class PageRank(Query):
     r_max: float = 1e-7
 
     def build(self) -> Algorithm:
-        def make_r0(ctx: AlgoContext):
-            r0 = np.zeros(ctx.V, dtype=np.float32)
-            r0[ctx.v2id] = 1.0 / ctx.orig_num_vertices
-            return r0
+        r_max = self.r_max
 
-        return _push_spec(self.alpha, self.r_max, make_r0)
+        def init(ctx: AlgoContext, src):
+            # the start value as float32 on the host: a division on the
+            # device may round 1 / n another way
+            u = np.float32(1.0 / ctx.orig_num_vertices)
+            r0 = jnp.where(ctx.is_real, u, jnp.float32(0))
+            last = _last_active_degree(u, r_max)
+            front0 = ctx.is_real & (ctx.degrees <= last)
+            return front0, {"p": jnp.zeros(ctx.V, jnp.float32), "r": r0}
+
+        # another name than PPR's: the two starts are two init hooks,
+        # and the service compiles a group's init into its programs
+        return _push_spec(self.alpha, self.r_max, init=init,
+                          name="pagerank")

@@ -47,9 +47,9 @@ class WCC(Query):
     minimum original id in each component."""
 
     def build(self) -> Algorithm:
-        def init(ctx: AlgoContext):
-            label0 = np.arange(ctx.V, dtype=np.int32)
-            front0 = np.ones(ctx.V, dtype=bool)  # all vertices active
+        def init(ctx: AlgoContext, src):
+            label0 = jnp.arange(ctx.V, dtype=jnp.int32)
+            front0 = jnp.ones(ctx.V, dtype=bool)  # all vertices active
             return front0, {"label": label0}
 
         def extract(state: StateT, ctx: AlgoContext):

@@ -24,8 +24,11 @@ An :class:`Algorithm` bundles:
                      positive priority (Alg. 1 lines 13-15),
   * ``priority``     per-vertex scheduling priority (higher = sooner),
   * ``init``         builds the initial ``(frontier, state)`` from an
-                     :class:`AlgoContext` — the algorithm owns its setup
-                     instead of callers poking at engine internals,
+                     :class:`AlgoContext` and the query's init data (a
+                     single-source query's ``source``, as an engine id)
+                     in ``jax.numpy``, so that it runs inside a compiled
+                     program — the algorithm owns its setup instead of
+                     callers poking at engine internals,
   * ``extract``      reads the converged state back out in ORIGINAL
                      vertex ids (the user-facing result domain).
 
@@ -65,14 +68,19 @@ class AlgoContext:
     All arrays live in the *engine* vertex domain (reordered entities
     followed by mini vertices, size ``V``); ``v2id`` maps original
     vertex ids into that domain so ``extract`` hooks can return results
-    indexed by original id. Built by ``GraphSession`` from the engine's
-    tables — user code never reads ``engine.V`` / ``hg.v2id`` directly.
+    indexed by original id. ``GraphSession.ctx`` holds the engine's
+    device tables themselves (``degrees``, ``is_real``) and the host's
+    ``v2id``; inside a compiled program (:meth:`Engine.algo_ctx
+    <repro.core.engine.Engine.algo_ctx>`) the two tables are the
+    program's table argument and ``v2id`` is ``None``, so an ``init``
+    hook reads ``V``, ``degrees``, ``is_real`` and ``orig_num_vertices``
+    only. User code never reads ``engine.V`` / ``hg.v2id`` directly.
     """
 
     V: int                       # engine vertex-domain size (incl. virtual)
-    degrees: np.ndarray          # int32[V] out-degree (0 for virtual)
-    is_real: np.ndarray          # bool[V]  False for virtual duplicates
-    v2id: np.ndarray             # int64[orig_num_vertices] -> engine id
+    degrees: Any                 # int32[V] out-degree (0 for virtual)
+    is_real: Any                 # bool[V]  False for virtual duplicates
+    v2id: np.ndarray | None      # int64[orig_num_vertices] -> engine id
     orig_num_vertices: int       # |V| of the input graph
 
     def engine_id(self, vertex: int) -> int:
@@ -165,15 +173,33 @@ class Algorithm:
     #: every value the callbacks close over (e.g. PPR's alpha/r_max) must
     #: appear here (or be folded into ``name``): the engine's compile
     #: cache keys on ``(name, params, cfg)``, so omitting a parameter
-    #: silently reuses another instance's compiled tick
+    #: silently reuses another instance's compiled tick. ``init`` is a
+    #: callback too: the service compiles it into its ``admit``
+    #: program, so two setups (PPR's point start, PageRank's uniform
+    #: one) need two names
     params: tuple = ()
-    #: (ctx) -> (frontier bool[V], state dict) — algorithm-owned setup.
-    #: Pure host-side numpy; does NOT affect the compiled tick, so it is
-    #: deliberately outside the compile-cache key (queries differing
-    #: only in init data, e.g. BFS sources, share one compilation)
-    init: Callable[[AlgoContext], tuple[np.ndarray, StateT]] | None = None
+    #: (ctx, src) -> (frontier bool[V], state dict) — algorithm-owned
+    #: setup, written in ``jax.numpy`` so that it traces: the service
+    #: runs it inside its compiled ``admit`` (``ctx`` then holds the
+    #: program's table arguments), ``GraphSession`` calls it on the
+    #: device tables. ``src`` is the init data :meth:`init_data` gives:
+    #: the source's engine id as a traced int32 scalar, or ``None`` for
+    #: a whole-graph query. It is outside the compile-cache key, so
+    #: queries differing only in it (BFS sources) share one compilation
+    init: Callable[[AlgoContext, Any], tuple[Any, StateT]] | None = None
     #: (state, ctx) -> user-facing result in ORIGINAL vertex ids
     extract: Callable[[StateT, AlgoContext], Any] | None = None
+    #: ORIGINAL id of the vertex a single-source query starts from;
+    #: ``None`` declares a whole-graph query, whose ``init`` takes no data
+    source: int | None = None
+
+    def init_data(self, ctx: AlgoContext) -> np.int32 | None:
+        """``init``'s second argument: the source's engine id as one
+        int32, or ``None`` for a whole-graph query — all that reaches
+        the device to start a query."""
+        if self.source is None:
+            return None
+        return np.int32(ctx.engine_id(self.source))
 
     def neutral(self, dtype) -> jnp.ndarray:
         if self.combine == "min":
@@ -210,21 +236,21 @@ def aggregation_eligible(algo: Algorithm) -> bool:
     return algo.combine == "min" and algo.on_process is None
 
 def lift_init(algos: list[Algorithm],
-              ctx: AlgoContext) -> tuple[np.ndarray, StateT]:
+              ctx: AlgoContext) -> tuple[Any, StateT]:
     """Auto-lift per-query ``init`` hooks into the batched init surface.
 
-    Runs every algorithm's own ``init(ctx)`` and stacks the results
-    along a leading Q axis: ``(frontier bool[Q, V], state {k: [Q, V]})``
-    — exactly the per-query arrays a solo run would start from, so the
-    batch plane's solo-equivalence contract starts from identical
-    inputs.
+    Runs every algorithm's own ``init(ctx, init_data)`` and stacks the
+    results along a leading Q axis: ``(frontier bool[Q, V], state {k:
+    [Q, V]})`` — exactly the per-query arrays a solo run would start
+    from, so the batch plane's solo-equivalence contract starts from
+    identical inputs.
     """
-    pairs = [a.init(ctx) for a in algos]
+    pairs = [a.init(ctx, a.init_data(ctx)) for a in algos]
     keys = set(pairs[0][1])
     if any(set(s) != keys for _, s in pairs):
         raise ValueError("batch members disagree on state keys")
-    front = np.stack([f for f, _ in pairs])
-    state = {k: np.stack([s[k] for _, s in pairs]) for k in pairs[0][1]}
+    front = jnp.stack([f for f, _ in pairs])
+    state = {k: jnp.stack([s[k] for _, s in pairs]) for k in pairs[0][1]}
     return front, state
 
 
@@ -246,7 +272,7 @@ class QueryBatch(Query):
     Homogeneous means equal ``(name, params)`` — multi-source BFS, or N
     PPR personalizations sharing ``(alpha, r_max)`` (the paper's
     per-user workload). Queries differing only in init data batch
-    together because ``init`` is outside the engine's compile key.
+    together because the init data is outside the engine's compile key.
     Heterogeneous submissions belong on
     :class:`~repro.core.service.GraphService`, which groups by key and
     drains one batch per group.
@@ -302,7 +328,7 @@ class QueryBatch(Query):
 
     # ---- batched hooks (override for vectorized setup/readout) -------
     def init_batch(self, algos: list[Algorithm],
-                   ctx: AlgoContext) -> tuple[np.ndarray, StateT]:
+                   ctx: AlgoContext) -> tuple[Any, StateT]:
         """Batched init: default auto-lifts the single-query hooks."""
         return lift_init(algos, ctx)
 

@@ -103,7 +103,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.api import Algorithm, aggregation_eligible
+from repro.core.api import AlgoContext, Algorithm, aggregation_eligible
 from repro.core.executor import EXECUTORS, ExecTables, Tile, make_executor
 from repro.core.pool import BufferPool
 from repro.core.scheduler import (S_CACHED, S_LOADING, PullView,
@@ -508,6 +508,17 @@ class Engine:
             tiles=self.tiles))
 
     # ------------------------------------------------------------------
+    def algo_ctx(self, tables: ExecTables) -> AlgoContext:
+        """The :class:`AlgoContext` an ``init`` hook reads: the degree
+        and realness tables of ``tables`` — :attr:`tables` itself, or a
+        compiled program's table argument, so that no program holds
+        them as constants. ``v2id`` is the host's (``GraphSession.ctx``
+        adds it) and ``None`` here."""
+        return AlgoContext(V=tables.V, degrees=tables.v_deg,
+                           is_real=tables.is_real, v2id=None,
+                           orig_num_vertices=self.hg.orig_num_vertices)
+
+    # ------------------------------------------------------------------
     @functools.partial(jax.profiler.annotate_function, name="engine.run")
     def run(self, algo: Algorithm, init_frontier: np.ndarray,
             init_state: dict) -> tuple[dict, Metrics, dict | None]:
@@ -515,7 +526,7 @@ class Engine:
         The host span ``engine.run`` covers the dispatch and the host
         copies of state and counters."""
         cfg = self.cfg
-        front0 = jnp.asarray(np.asarray(init_frontier, dtype=bool))
+        front0 = jnp.asarray(init_frontier, dtype=bool)
         state0 = {k: jnp.asarray(v) for k, v in init_state.items()}
         # two ppr_algorithm() closures with different alpha/r_max share a
         # name but must not share a compiled tick — Algorithm.params folds
@@ -888,12 +899,11 @@ class Engine:
                 raise ValueError(
                     "batch_mode='aggregated' is asynchronous-only; "
                     "sync=True requires the per-query plane")
-        fronts = np.asarray(init_fronts, dtype=bool)
-        if fronts.ndim != 2:
+        front0 = jnp.asarray(init_fronts, dtype=bool)
+        if front0.ndim != 2:
             raise ValueError(
-                f"init_fronts must be [Q, V], got shape {fronts.shape}")
-        Q = int(fronts.shape[0])
-        front0 = jnp.asarray(fronts)
+                f"init_fronts must be [Q, V], got shape {front0.shape}")
+        Q = int(front0.shape[0])
         state0 = {k: jnp.asarray(v) for k, v in init_states.items()}
         key = ("batch", mode, Q, algo.name, algo.params, cfg)
         if key not in self._compiled:
@@ -1268,8 +1278,8 @@ class Engine:
         ContinuousService`) never drains, so it cannot live inside one
         ``while_loop``; instead the host loop calls these per tick:
 
-          * ``carry0(fronts0, states0) -> carry`` — a fresh Q-capacity
-            carry (all-dead rows when the fronts are empty);
+          * ``carry0() -> carry`` — a fresh Q-capacity carry of dead
+            rows, made on the device;
           * ``step(carry) -> (carry', pending[Q], used_slots,
             stalled[Q])`` — ONE engine tick, the exact batch-plane step
             body (per-query plane: alive-masked solo ticks + shared-I/O
@@ -1278,19 +1288,24 @@ class Engine:
             host's retirement / budget decisions, and which rows stopped
             at ``max_ticks`` with work pending (the host raises: such a
             row holds a partial state, not a result);
-          * ``admit(carry, q, front0, state0) -> carry`` — stack a
-            fresh query into row ``q`` at a tick boundary. Per-query
-            plane: the row becomes the solo tick-0 carry verbatim, so
-            everything after is bit-identical to a solo run no matter
-            when it was admitted. Aggregated plane: only the per-query
-            leaves are replaced and the shared block states are
-            re-activated against the new cross-query refcount
-            (:meth:`Scheduler.reactivate_on_admit`) — the newcomer's
-            blocks wake without disturbing the running schedule.
-            Admitting an all-False frontier resets the row to dead,
-            which is how the per-query plane retires;
-          * ``retire(carry, q) -> carry`` (aggregated only) — clear the
-            row's frontier/worklist and release residency no live query
+          * ``admit(carry, q, src) -> carry`` — stack a fresh query
+            into row ``q`` at a tick boundary. ``algo.init`` runs inside
+            the program on the tables and ``src``, the query's init
+            data (:meth:`Algorithm.init_data`: the source's engine id,
+            or ``None``), so a query's start costs the host one int32.
+            Per-query plane: the row becomes the solo tick-0 carry
+            verbatim, so everything after is bit-identical to a solo
+            run no matter when it was admitted. Aggregated plane: only
+            the per-query leaves are replaced and the shared block
+            states are re-activated against the new cross-query
+            refcount (:meth:`Scheduler.reactivate_on_admit`) — the
+            newcomer's blocks wake without disturbing the running
+            schedule;
+          * ``retire(carry, q) -> carry`` — take row ``q`` out, with no
+            host array. Per-query plane: the row resets to a dead
+            tick-0 carry (zero frontier, zero state of ``init``'s
+            shapes and dtypes). Aggregated plane: clear the row's
+            frontier/worklist and release residency no live query
             needs (:meth:`Scheduler.reclaim_idle`), so a service that
             never drains gives slots back at retirement instead of
             ratcheting the shared pool full;
@@ -1308,9 +1323,9 @@ class Engine:
         Each is a compiled program taking the engine's tables as its
         first argument, bound here. Compiled once per ``(Q, mode, name,
         params, cfg)`` and cached — admissions and retirements at a
-        given capacity never recompile (``q`` is traced, so one program
-        serves every row); capacity changes (the serving layer's
-        power-of-two ladder) do.
+        given capacity never recompile (``q`` and ``src`` are traced, so
+        one program serves every row and source); capacity changes (the
+        serving layer's power-of-two ladder) do.
         """
         key = ("svc", mode, Q, algo.name, algo.params, self.cfg)
         if key in self._compiled:
@@ -1325,6 +1340,17 @@ class Engine:
                 "serve it on the per-query plane (see Engine.run_batch)")
         i32 = jnp.int32
         incremental = self.cfg.refresh == "incremental"
+        start = lambda tables, src: algo.init(self.algo_ctx(tables), src)
+
+        def dead(tables, lead=()):
+            """A dead row's frontier and state: zeros of ``init``'s
+            shapes and dtypes, with ``lead`` axes in front."""
+            src = None if algo.source is None \
+                else jax.ShapeDtypeStruct((), i32)
+            front, state = jax.eval_shape(
+                lambda s: start(tables, s), src)
+            zeros = lambda a: jnp.zeros(lead + a.shape, a.dtype)
+            return zeros(front), jax.tree_util.tree_map(zeros, state)
 
         if mode == "aggregated":
             slots = self._agg_slots(Q)
@@ -1335,11 +1361,12 @@ class Engine:
                     return (c2, jnp.any(c2["front"], axis=-1),
                             c2["used_slots"], jnp.zeros(Q, bool))
 
-            def carry0(tables, fronts0, states0):
-                return self._agg_carry0(algo, tables, fronts0, states0)
+            def carry0(tables):
+                return self._agg_carry0(algo, tables, *dead(tables, (Q,)))
 
-            def admit(tables, c, q, front0, state0):
+            def admit(tables, c, q, src):
                 sched = self._tiers(tables)[0]
+                front0, state0 = start(tables, src)
                 front0 = front0 & tables.is_real
                 nact0, prio0 = sched.refresh(algo, state0, front0)
                 z = jnp.zeros((), jnp.uint32)
@@ -1380,10 +1407,11 @@ class Engine:
                     return (c2, alive, jnp.sum(c2["used_slots"]),
                             self._work_pending(c2) & ~alive)
 
-            def carry0(tables, fronts0, states0):
-                return self._batch_carry0(algo, tables, fronts0, states0)
+            def carry0(tables):
+                return self._batch_carry0(algo, tables,
+                                          *dead(tables, (Q,)))
 
-            def admit(tables, c, q, front0, state0):
+            def put_row(tables, c, q, front0, state0):
                 row = self._initial_carry(algo, tables, front0, state0)
                 z = jnp.zeros((), jnp.uint32)
                 cnt = dict(row["counters"])
@@ -1393,10 +1421,14 @@ class Engine:
                 return jax.tree_util.tree_map(
                     lambda full, r: full.at[q].set(r), c, row)
 
+            def admit(tables, c, q, src):
+                return put_row(tables, c, q, *start(tables, src))
+
             # per-query retirement IS an admit of the empty query: the
             # row resets to a dead tick-0 carry (all-INACTIVE block
             # states), which also zeroes its private pool accounting
-            retire = None
+            def retire(tables, c, q):
+                return put_row(tables, c, q, *dead(tables))
 
         row_trace = self.cfg.trace and mode == "per_query"
 
@@ -1412,8 +1444,7 @@ class Engine:
 
         bound = lambda f: functools.partial(jax.jit(f), self.tables)
         fns = dict(carry0=bound(carry0), step=bound(step),
-                   admit=bound(admit),
-                   retire=None if retire is None else bound(retire),
+                   admit=bound(admit), retire=bound(retire),
                    extract=bound(extract))
         self._compiled[key] = fns
         return fns

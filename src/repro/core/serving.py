@@ -23,21 +23,28 @@ the next tick boundary via :meth:`Engine.service_fns`'s ``admit`` — its
 row becomes the solo tick-0 carry verbatim (per-query plane), so every
 tick it subsequently takes is the solo tick body on the solo carry and
 the result is bit-identical to ``session.run`` *no matter when it was
-admitted*. On the aggregated plane only the per-query leaves are
+admitted*. The row's start is built inside that program: ``admit`` runs
+the algorithm's ``init`` on the device tables and the query's init data
+(:meth:`Algorithm.init_data`, the source's engine id), so admitting a
+query sends the device one int32, not a V-long frontier and state
+(``stats()["admit_host_bytes"]`` counts what admissions and resets
+send). On the aggregated plane only the per-query leaves are
 replaced and the newcomer's frontier blocks are re-activated against the
 shared schedule's cross-query refcount — the running pull order absorbs
 the new worklist without restarting. Retirement is the reverse edge:
 the moment a row's liveness flag drops, the device slices out the row's
 state and counters (``service_fns``'s ``extract``), the host copies that
 one row and builds a full :class:`~repro.core.session.RunResult`,
-resolves the handle, and kills the row — the service keeps ticking
+resolves the handle, and kills the row with ``retire``, one dispatch
+that makes the dead row on the device — the service keeps ticking
 throughout.
 
 **Capacity ladder.** The compiled step is shaped ``[Q, ...]``, so Q is a
 compile-time constant. Capacities move on a power-of-two ladder
 (``service_fns`` caches per capacity): admission beyond the current
 capacity doubles it, retirement below half of it halves it. A resize is
-an eager tree op — a fresh ``carry0(newQ)`` with the live rows gathered
+an eager tree op — a fresh ``carry0()`` of newQ dead rows, made on the
+device, with the live rows gathered
 in (aggregated: only :attr:`Engine.AGG_PER_QUERY_KEYS` leaves move; the
 ONE shared control plane, including block states and pool occupancy,
 carries through untouched) — so each capacity compiles exactly once and
@@ -143,7 +150,7 @@ class _Group:
     independent in-flight queries of one ``(name, params)`` key."""
 
     __slots__ = ("key", "algo", "mode", "capacity", "carry", "rows",
-                 "algos", "pending", "used_slots", "state_zero", "fns")
+                 "algos", "pending", "used_slots", "fns")
 
     def __init__(self, key, algo: Algorithm, mode: str):
         self.key = key
@@ -155,7 +162,6 @@ class _Group:
         self.algos: list[Algorithm | None] = []  # each row's built algo
         self.pending = np.zeros(0, bool)   # last step's liveness
         self.used_slots = 0                # last step's pool occupancy
-        self.state_zero: dict | None = None  # per-row zero state template
         self.fns: dict | None = None
 
     @property
@@ -208,6 +214,7 @@ class ContinuousService:
         self.peak_capacity = 0
         self.peak_service_slots = 0
         self.retire_host_bytes = 0        # bytes retirement copied out
+        self.admit_host_bytes = 0         # bytes admission/reset sent in
 
     # ------------------------------------------------------------------
     @property
@@ -323,10 +330,9 @@ class ContinuousService:
 
     def _admit(self, g: _Group, slot: int, handle: QueryHandle,
                algo: Algorithm, busy: bool) -> None:
-        front0, state0 = algo.init(self.session.ctx)
-        front0 = jnp.asarray(np.asarray(front0, dtype=bool))
-        state0 = {k: jnp.asarray(v) for k, v in state0.items()}
-        g.carry = g.fns["admit"](g.carry, slot, front0, state0)
+        src = algo.init_data(self.session.ctx)
+        self.admit_host_bytes += 0 if src is None else src.nbytes
+        g.carry = g.fns["admit"](g.carry, slot, src)
         g.rows[slot] = handle
         g.algos[slot] = algo
         g.pending[slot] = True
@@ -427,15 +433,8 @@ class ContinuousService:
                                   metrics, trace)
 
     def _kill_row(self, g: _Group, q: int) -> None:
-        if g.mode == "aggregated":
-            g.carry = g.fns["retire"](g.carry, q)
-        else:
-            # per-query retirement IS an admission of the empty query:
-            # the row resets to a dead tick-0 carry, zeroing its private
-            # pool accounting with it
-            front0 = jnp.zeros(self.session.engine.V, bool)
-            state0 = {k: jnp.asarray(v) for k, v in g.state_zero.items()}
-            g.carry = g.fns["admit"](g.carry, q, front0, state0)
+        # one dispatch, no host array: the dead row is made on the device
+        g.carry = g.fns["retire"](g.carry, q)
         g.rows[q] = None
         g.algos[q] = None
         g.pending[q] = False
@@ -458,16 +457,8 @@ class ContinuousService:
         """Move ``g`` to capacity ``newcap``, gathering the live rows in
         ``perm`` into the low slots of a fresh carry. Grow passes the
         identity perm; shrink passes the surviving rows' indices."""
-        eng = self.session.engine
-        fns = eng.service_fns(algo, newcap, g.mode)
-        if g.state_zero is None:
-            _, s0 = algo.init(self.session.ctx)
-            g.state_zero = {k: np.zeros_like(np.asarray(v))
-                            for k, v in s0.items()}
-        fronts0 = jnp.zeros((newcap, eng.V), bool)
-        states0 = {k: jnp.asarray(np.zeros((newcap,) + v.shape, v.dtype))
-                   for k, v in g.state_zero.items()}
-        fresh = fns["carry0"](fronts0, states0)
+        fns = self.session.engine.service_fns(algo, newcap, g.mode)
+        fresh = fns["carry0"]()
         if g.carry is not None and perm:
             idx = jnp.asarray(np.asarray(perm, np.int32))
             k = len(perm)
@@ -526,7 +517,8 @@ class ContinuousService:
                  resizes=self.resizes,
                  peak_capacity=self.peak_capacity,
                  peak_service_slots=self.peak_service_slots,
-                 retire_host_bytes=self.retire_host_bytes)
+                 retire_host_bytes=self.retire_host_bytes,
+                 admit_host_bytes=self.admit_host_bytes)
         pct = self.latency_percentiles()
         d["latency_ticks_p50"] = pct["p50"]
         d["latency_ticks_p99"] = pct["p99"]

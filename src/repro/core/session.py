@@ -48,8 +48,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Iterable, Sequence
 
-import numpy as np
-
 from repro.core.api import (AlgoContext, Algorithm, Query, QueryBatch,
                             aggregation_eligible)
 from repro.core.engine import Engine, EngineConfig, Metrics, batch_totals
@@ -169,15 +167,12 @@ class GraphSession:
 
     @property
     def ctx(self) -> AlgoContext:
-        """The algorithm-facing view of this graph (built once)."""
+        """The algorithm-facing view of this graph (built once): the
+        engine's device tables and the host's id map."""
         if self._ctx is None:
             eng = self.engine
-            self._ctx = AlgoContext(
-                V=eng.V,
-                degrees=np.asarray(eng.tables.v_deg, dtype=np.int32),
-                is_real=np.asarray(eng.tables.is_real),
-                v2id=self.hg.v2id,
-                orig_num_vertices=self.hg.orig_num_vertices)
+            self._ctx = dataclasses.replace(eng.algo_ctx(eng.tables),
+                                            v2id=self.hg.v2id)
         return self._ctx
 
     @property
@@ -214,7 +209,7 @@ class GraphSession:
         """Single-pass execution of a self-describing Algorithm."""
         assert algo.init is not None, \
             f"algorithm {algo.name!r} has no init hook; use engine.run"
-        frontier, state = algo.init(self.ctx)
+        frontier, state = algo.init(self.ctx, algo.init_data(self.ctx))
         out_state, metrics, trace = self.engine.run(algo, frontier, state)
         result = algo.extract(out_state, self.ctx) \
             if algo.extract is not None else out_state

@@ -502,7 +502,7 @@ def _lowered_solo_bfs(scale):
                         EngineConfig(bucketing=0))
     eng = sess.engine
     algo = BFS(0).build()
-    front0, state0 = algo.init(sess.ctx)
+    front0, state0 = algo.init(sess.ctx, algo.init_data(sess.ctx))
     lowered = jax.jit(functools.partial(eng._run_impl, algo)).lower(
         eng.tables, front0, state0)
     return eng.tables.all_edges.shape[0], lowered.as_text()

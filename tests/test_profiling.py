@@ -123,7 +123,7 @@ def _stages(op_names) -> set:
 def test_solo_run_carries_stage_scopes(graph):
     sess = session(graph)
     eng, algo = sess.engine, BFS(0).build()
-    front0, state0 = algo.init(sess.ctx)
+    front0, state0 = algo.init(sess.ctx, algo.init_data(sess.ctx))
     run = jax.jit(lambda t, f, s: eng._run_impl(algo, t, f, s))
     names = _op_names(run.lower(eng.tables, front0, state0).compile())
     assert _stages(names) == {"schedule", "execute", "refresh"}
@@ -139,11 +139,7 @@ def test_service_step_carries_stage_scopes(graph, mode, want):
     sess = session(graph, batch_mode="aggregated", pool_mode="shared")
     eng, algo = sess.engine, BFS(0).build()
     fns = eng.service_fns(algo, 2, mode)
-    _, s0 = algo.init(sess.ctx)
-    carry = fns["carry0"](
-        np.zeros((2, eng.V), bool),
-        {k: np.zeros((2,) + np.shape(v), np.asarray(v).dtype)
-         for k, v in s0.items()})
+    carry = fns["carry0"]()
     names = _op_names(fns["step"].func.lower(eng.tables, carry).compile())
     assert _stages(names) == want
     if mode == "per_query":

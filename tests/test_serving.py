@@ -20,11 +20,12 @@ The contracts under test:
 """
 import dataclasses
 import functools
+import re
 
 import numpy as np
 import pytest
 
-from repro.algorithms import BFS, MIS, PPR, WCC
+from repro.algorithms import BFS, MIS, PPR, PageRank, WCC
 from repro.core import (ContinuousService, EngineConfig, GraphService,
                         GraphSession, QueryBatch, QueryState, ServeConfig)
 from repro.core.engine import TRACE_LEN, Metrics
@@ -177,6 +178,55 @@ def test_steady_state_admissions_never_recompile(monkeypatch):
     assert set(slots) == {0, 1}
     (g,) = svc._groups.values()
     assert g.fns["extract"].func._cache_size() == 1
+    # the source is traced too: six sources, one admit program; and the
+    # row's reset is its own program, compiled once
+    assert g.fns["admit"].func._cache_size() == 1
+    assert g.fns["retire"].func._cache_size() == 1
+
+
+# ----------------------------------------------------------------------
+# admission sends the init data alone; a reset sends nothing
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("plane", ["per_query", "aggregated"])
+def test_admission_uploads_no_vertex_array(monkeypatch, plane):
+    """``admit_host_bytes`` counts what admissions and resets copy to the
+    device: one int32 source per single-source admission, nothing for a
+    whole-graph one, nothing per reset. No program holds a V-long
+    constant in place of the upload."""
+    kw = dict(AGG) if plane == "aggregated" else {}
+    svc = ContinuousService(
+        GraphSession(_graph(True), EngineConfig(**CFG, **kw),
+                     block_edges=64),
+        serve=ServeConfig(initial_capacity=2, max_capacity=2))
+    if plane == "per_query":
+        queries = [PPR(source=s, alpha=0.15, r_max=1e-3) for s in SOURCES]
+        queries.append(PageRank(alpha=0.15, r_max=1e-3))
+    else:
+        queries = [BFS(s) for s in SOURCES]
+    resets = []
+    real = svc._kill_row
+
+    def kill(g, q):
+        before = svc.admit_host_bytes
+        real(g, q)
+        resets.append(svc.admit_host_bytes - before)
+    monkeypatch.setattr(svc, "_kill_row", kill)
+    for qy in queries:
+        svc.submit(qy)
+    svc.run_until_idle()
+    st = svc.stats()
+    assert st["completed"] == len(queries) == len(resets)
+    assert resets == [0] * len(queries)
+    assert st["admit_host_bytes"] == len(SOURCES) * np.int32(0).nbytes
+    eng = svc.session.engine
+    for g in svc._groups.values():
+        for name, args in (("admit", (g.carry, 0, g.algo.init_data(
+                svc.session.ctx))), ("retire", (g.carry, 0)),
+                           ("carry0", ())):
+            text = g.fns[name].func.lower(eng.tables, *args).as_text()
+            assert not re.search(rf"constant[^\n]*tensor<{eng.V}x", text), \
+                name
 
 
 # ----------------------------------------------------------------------
@@ -279,7 +329,7 @@ def test_retirement_copies_one_row_to_the_host():
         svc.submit(PPR(source=s, alpha=0.15, r_max=1e-3))
     svc.run_until_idle()
     (g,) = svc._groups.values()
-    state_bytes = sum(v.nbytes for v in g.state_zero.values())
+    state_bytes = sum(v[0].nbytes for v in g.carry["state"].values())
     assert state_bytes == 2 * 4 * svc.session.engine.V   # p, r: f32[V]
     counter_bytes = len(dataclasses.fields(Metrics)) * 2 * 4  # u32 limbs
     st = svc.stats()
